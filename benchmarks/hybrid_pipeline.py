@@ -59,23 +59,23 @@ def run() -> dict:
     # cache would read as zero launches.
     jax.clear_caches()
     sc_ops.reset_launch_counts()
-    _, _, stats = vgg9_infer_hybrid(params, imgs, CFG, interpret=True,
-                                    plan=plan, return_stats=True)
+    _, _, stats = vgg9_infer_hybrid(params, imgs, CFG, plan=plan,
+                                    return_stats=True)
     fused_launches = sc_ops.launch_counts().get("spike_matmul_mapped", 0)
 
     sc_ops.reset_launch_counts()
-    vgg9_infer_hybrid_unfused(params, imgs, CFG, interpret=True)
+    vgg9_infer_hybrid_unfused(params, imgs, CFG)
     unfused_launches = sc_ops.launch_counts().get("spike_matmul", 0)
 
     skip_rates = {k: float(v["skip_rate"]) for k, v in stats.items()
                   if "skip_rate" in v}
 
-    # --- wall clock. NOTE: kernels run in interpret mode on this CPU
-    # container, so absolute times are a correctness harness, not a perf
-    # signal — the TPU-relevant perf metrics are the launch counts and the
-    # tile-skip rates (work the MXU never sees).
-    fused_fn = lambda: vgg9_infer_hybrid(params, imgs, CFG, interpret=True, plan=plan)
-    unfused_fn = lambda: vgg9_infer_hybrid_unfused(params, imgs, CFG, interpret=True)
+    # --- wall clock. NOTE: off a TPU the kernels run in the Pallas
+    # interpreter, so there absolute times are a correctness harness, not a
+    # perf signal — the TPU-relevant perf metrics are the launch counts and
+    # the tile-skip rates (work the MXU never sees).
+    fused_fn = lambda: vgg9_infer_hybrid(params, imgs, CFG, plan=plan)
+    unfused_fn = lambda: vgg9_infer_hybrid_unfused(params, imgs, CFG)
     fused_us = time_fn(fused_fn, iters=3, warmup=1)
     unfused_us = time_fn(unfused_fn, iters=3, warmup=1)
 
@@ -106,4 +106,6 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -106,6 +106,8 @@ def main() -> None:
         from .common import RESULTS_PATH
         raise SystemExit(gate_main(args.results or RESULTS_PATH,
                                    args.threshold))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_benchmarks()
 
 

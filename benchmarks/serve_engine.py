@@ -182,7 +182,7 @@ def bench_snn(smoke: bool) -> dict:
         vgg9_snn.TINY, img_hw=32, stages=(16, 24, "MP", 32, 32, "MP"), fc_dim=64)
     params = init_vgg9(jax.random.PRNGKey(0), cfg)
     slots = 2 if smoke else 4
-    runner = SNNRunner(cfg, params, interpret=True)
+    runner = SNNRunner(cfg, params)
     n_req = 3 * slots
     payloads, options = _mixed_trace(cfg, n_req)
 
@@ -793,6 +793,11 @@ def bench_fleet(smoke: bool) -> dict:
     from repro.serve.router import make_router, make_worker_fleet
     from repro.serve.worker import build_runner, lm_spec
 
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "bench_fleet runs an in-process fleet in this process and worker "
+            "subprocesses beside it; a TPU belongs to one process, so run "
+            "this pass on the CPU (JAX_PLATFORMS=cpu)")
     cfg = _lm_cfg()
     tokens = 4 if smoke else 8
     n_req = 4 if smoke else 6
@@ -929,6 +934,8 @@ def run(smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes for CI (2 slots, fewer requests)")

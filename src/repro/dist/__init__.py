@@ -11,12 +11,10 @@ forward functions run unmodified on one device or a pod:
 * ``compression`` — error-feedback int8 gradient compression
   (``quantize_error_feedback``) and the quantize → psum → dequantize
   all-reduce (``compressed_psum``) used inside ``shard_map`` train steps.
-* ``compat``      — forward-compat shims for older jax (installed on import).
 
 Every rule degrades to replicated/no-op behavior when axes are absent or
 dims don't divide, so the same call sites work on one CPU device and on a
 mesh (tests/test_dist.py runs the multi-device cases in subprocesses with
 ``XLA_FLAGS=--xla_force_host_platform_device_count``).
 """
-from . import compat  # noqa: F401  (installs jax API shims first)
 from . import compression, context, sharding  # noqa: F401

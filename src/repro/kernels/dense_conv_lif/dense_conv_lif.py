@@ -21,11 +21,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import F32_DOT
+
 
 def _dense_conv_lif_kernel(x_ref, w_ref, b_ref, s_ref, u_ref, *, num_steps, beta, theta):
     """Grid step (i, j): currents = x[i] @ w[:, j] + bias[j]; run T LIF steps."""
     current = jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+        precision=F32_DOT,
     ) + b_ref[...]
 
     u = jnp.zeros_like(current)
@@ -80,5 +83,6 @@ def dense_conv_lif(
             jax.ShapeDtypeStruct((m, n), jnp.float32),
         ],
         interpret=interpret,
+        name="dense_conv_lif",
     )(patches, weights, bias.reshape(1, n))
     return spikes, u

@@ -49,6 +49,7 @@ def lif_step_fused(
             jax.ShapeDtypeStruct((r, c), u.dtype),
         ],
         interpret=interpret,
+        name="lif_step",
     )(u, current, prev_spike)
 
 
@@ -99,4 +100,5 @@ def lif_epilogue_fused(
             jax.ShapeDtypeStruct((r, c), u.dtype),
         ],
         interpret=interpret,
+        name="lif_epilogue",
     )(u, current, prev_spike, bias)

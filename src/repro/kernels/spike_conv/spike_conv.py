@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import F32_DOT
+
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_K = 128
 DEFAULT_BLOCK_N = 128
@@ -41,7 +43,8 @@ def _spike_matmul_kernel(x_ref, w_ref, o_ref, *, gate: bool):
 
     def _accumulate():
         o_ref[...] += jnp.dot(
-            x, w_ref[...], preferred_element_type=jnp.float32
+            x, w_ref[...], preferred_element_type=jnp.float32,
+            precision=F32_DOT,
         ).astype(o_ref.dtype)
 
     if gate:
@@ -85,6 +88,7 @@ def spike_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="spike_matmul",
     )(patches, weights)
 
 
@@ -92,11 +96,12 @@ def spike_matmul(
 # Occupancy-mapped variant: the gate moves out of the kernel body
 # ---------------------------------------------------------------------------
 
-def _spike_matmul_mapped_kernel(occ_ref, lidx_ref, x_ref, w_ref, o_ref):
+def _spike_matmul_mapped_kernel(occ_ref, lidx_ref, x_ref, w_ref, o_ref, *,
+                                nk: int):
     """Grid step gated by the *prefetched* occupancy map.
 
-    `occ_ref[i, kk]` decides whether this (block_m x block_k) spike tile
-    contributes. The in-kernel `jnp.any` test of the plain `spike_matmul` is
+    `occ_ref[i * nk + kk]` decides whether this (block_m x block_k) spike
+    tile contributes. The in-kernel `jnp.any` test of the plain `spike_matmul` is
     gone: empty tiles skip the MXU dot, and — because the index maps route
     their loads through `lidx_ref` (the last occupied k-tile) — the VMEM DMA
     for both the spike tile and the weight tile is elided too (Pallas skips a
@@ -109,10 +114,11 @@ def _spike_matmul_mapped_kernel(occ_ref, lidx_ref, x_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(occ_ref[i, kk] != 0)
+    @pl.when(occ_ref[i * nk + kk] != 0)
     def _accumulate():
         o_ref[...] += jnp.dot(
-            x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+            x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+            precision=F32_DOT,
         ).astype(o_ref.dtype)
 
 
@@ -134,6 +140,11 @@ def spike_matmul_mapped(
     block i (0 when none) — ops.skip_load_indices computes it. It keeps the
     input/weight block index constant across runs of empty tiles so the
     pipeline issues no DMA for them.
+
+    Both maps are scalar-prefetched into SMEM flattened to 1-D (index
+    ``i * nk + kk``): SMEM pads each row of a 2-D array to 128 words, which
+    at a 64-slot conv1 batch ([1024, 5] maps) would take 512 KiB per map
+    and overflow the 1 MiB SMEM.
     """
     m, k = patches.shape
     k2, n = weights.shape
@@ -149,16 +160,17 @@ def spike_matmul_mapped(
         grid=(nm, n // block_n, nk),
         in_specs=[
             pl.BlockSpec((block_m, block_k),
-                         lambda i, j, kk, occ, lidx: (i, lidx[i, kk])),
+                         lambda i, j, kk, occ, lidx: (i, lidx[i * nk + kk])),
             pl.BlockSpec((block_k, block_n),
-                         lambda i, j, kk, occ, lidx: (lidx[i, kk], j)),
+                         lambda i, j, kk, occ, lidx: (lidx[i * nk + kk], j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda i, j, kk, occ, lidx: (i, j)),
     )
     return pl.pallas_call(
-        _spike_matmul_mapped_kernel,
+        functools.partial(_spike_matmul_mapped_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(occupancy, load_idx, patches, weights)
+        name="spike_matmul_mapped",
+    )(occupancy.reshape(-1), load_idx.reshape(-1), patches, weights)

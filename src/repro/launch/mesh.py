@@ -9,8 +9,6 @@ from __future__ import annotations
 
 import jax
 
-from ..dist import compat as _compat  # noqa: F401  (jax API shims)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

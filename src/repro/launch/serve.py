@@ -44,12 +44,14 @@ import json
 from typing import Callable, List
 
 import jax
+import numpy as np
 
 from ..configs import get_arch
 from ..dist.context import compute_mesh
 from ..models import transformer as tf
 from ..serve.api import EngineConfig
 from ..serve.core import EngineCore
+from .compile_cache import enable_compile_cache
 from .mesh import make_data_mesh
 from .train import reduce_cfg
 
@@ -178,13 +180,12 @@ def serve_lm(args) -> None:
         sampling_opts = {"temperature": args.temperature,
                          "top_k": args.top_k, "top_p": args.top_p}
 
-    rng = jax.random.PRNGKey(args.seed + 1)
-    prompts = []
-    for i in range(args.requests):
-        rng, k1, k2 = jax.random.split(rng, 3)
-        length = int(jax.random.randint(k1, (), 1, 6))
-        prompts.append([int(t) for t in
-                        jax.random.randint(k2, (length,), 1, cfg.vocab)])
+    # NumPy, not jax.random: the parent of a --workers fleet must stay off
+    # the device, which belongs to one process (its worker)
+    rng = np.random.default_rng(args.seed + 1)
+    prompts = [[int(t) for t in rng.integers(1, cfg.vocab,
+                                             size=int(rng.integers(1, 6)))]
+               for _ in range(args.requests)]
     deadline = args.slo_ms / 1000.0 if args.slo_ms > 0 else None
     if deadline is not None and runner is not None:
         # (the --precision path pre-warms both variants' bucketed widths via
@@ -259,13 +260,13 @@ def serve_snn(args) -> None:
         from ..serve.precision import make_snn_pricer, make_snn_variants
         params = init_vgg9(jax.random.PRNGKey(args.seed), cfg)
         core, controller = precision_engine(
-            lambda: make_snn_variants(cfg, params, interpret=True),
+            lambda: make_snn_variants(cfg, params),
             make_snn_pricer(cfg), args)
     else:
         from ..models.vgg9 import init_vgg9
         from ..serve.runners.snn import SNNRunner
         params = init_vgg9(jax.random.PRNGKey(args.seed), cfg)
-        runner = SNNRunner(cfg, params, interpret=True)
+        runner = SNNRunner(cfg, params)
         core = build_engine(runner, args)
 
     if args.data_shard > 1:
@@ -279,11 +280,12 @@ def serve_snn(args) -> None:
     else:
         mesh_ctx = contextlib.nullcontext()
 
-    keys = jax.random.split(jax.random.PRNGKey(args.seed + 1), args.requests)
+    # NumPy images: a --workers parent never touches the device (see serve_lm)
+    rng = np.random.default_rng(args.seed + 1)
     shape = (cfg.img_hw, cfg.img_hw, cfg.in_ch)
     ids = []
-    for i, k in enumerate(keys):
-        img = jax.random.uniform(k, shape)
+    for i in range(args.requests):
+        img = rng.uniform(size=shape).astype(np.float32)
         opts = {}
         if args.precision and i % 3 == 0:
             # exercise the never-switch invariant from the CLI: every third
@@ -483,6 +485,7 @@ def main():
     args = ap.parse_args()
     for rule in check_flags(args):
         ap.error(rule.error)
+    enable_compile_cache()
 
     if args.workload == "snn":
         serve_snn(args)

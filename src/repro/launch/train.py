@@ -31,6 +31,7 @@ from ..train.optim import make_optimizer
 from ..train.schedule import warmup_cosine
 from ..train.train_step import (init_train_state, make_train_step,
                                 shard_map_compressed_step, stack_error_state)
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -80,6 +81,7 @@ def main():
                          "scale — tighter for tensors with wide channel "
                          "magnitude spread")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.compress_per_channel and not args.compress_grads:
         ap.error("--compress-per-channel requires --compress-grads")
 

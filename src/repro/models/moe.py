@@ -84,13 +84,7 @@ def moe_apply(p: Dict, x: jax.Array, *, top_k: int, act: str, n_experts: int,
 
         p = dict(p)
         p["experts"] = jax.tree_util.tree_map_with_path(_gather_spec, p["experts"])
-    from ..dist import compat as _compat
-    if (mesh is not None and "data" in mesh.axis_names
-            # partially-auto shard_map (manual dp, auto 'model') trips a
-            # fatal SPMD-partitioner check on the old XLA the compat shims
-            # target; there, tensor-parallel MoE falls back to pure GSPMD
-            and not (_compat.SHIMMED and "model" in mesh.axis_names
-                     and mesh.shape["model"] > 1)):
+    if mesh is not None and "data" in mesh.axis_names:
         from jax.sharding import PartitionSpec as P
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         ndp = 1

@@ -210,16 +210,20 @@ def _stage_plan(cfg: VGG9Config):
     return plan
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "plan", "interpret", "with_stats"))
+@functools.partial(jax.jit, static_argnames=("cfg", "plan", "with_stats"))
 def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
-                        plan, interpret: bool, with_stats: bool):
+                        plan, with_stats: bool):
     """The fused serving graph. See vgg9_infer_hybrid for the contract.
     with_stats is static: the no-stats trace returns an empty stats dict, so
-    XLA drops the occupancy/row maps and per-image reductions entirely."""
+    XLA drops the occupancy/row maps and per-image reductions entirely.
+    Whether the kernels lower through Mosaic or run in the Pallas
+    interpreter is read from the backend at trace time."""
+    from ..kernels import interpret_mode
     from ..kernels.dense_conv_lif.ops import input_layer_conv_lif
     from ..kernels.lif_step.ops import lif_epilogue
     from ..kernels.spike_conv.ops import spike_conv2d_mapped
 
+    interpret = interpret_mode()
     qp = quantized_view(params, cfg)
     b = images.shape[0]
     t = cfg.timesteps
@@ -281,7 +285,9 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
     for name in ("fc0", "fc1"):
         w2d = qp[name]["w"]
         in_per_image = flat.reshape(t, b, -1).sum(axis=(0, 2))
-        cur = flat @ w2d                                 # one launch, bias in epilogue
+        # one launch, bias in the epilogue; float32 like the kernels (XLA's
+        # default on a TPU would round the weights to bf16)
+        cur = jnp.dot(flat, w2d, precision=jax.lax.Precision.HIGHEST)
         s_seq = lif_scan_fused(cur.reshape(t, b, w2d.shape[-1]), qp[name]["b"])
         counts[name] = jnp.sum(s_seq)
         if with_stats:
@@ -298,7 +304,7 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
 
 
 def vgg9_infer_hybrid(params: Dict, images: jax.Array, cfg: VGG9Config, *,
-                      interpret: bool = True, plan=None, return_stats: bool = False):
+                      plan=None, return_stats: bool = False):
     """Fused inference via the TPU kernels: dense_conv_lif for the input
     layer, occupancy-mapped spike_conv + conv-epilogue LIF for the spiking
     layers. The whole graph is one jit (static `cfg`/`plan` hashing), with
@@ -317,8 +323,7 @@ def vgg9_infer_hybrid(params: Dict, images: jax.Array, cfg: VGG9Config, *,
         from ..core.hybrid import plan_vgg9_inference
         plan = plan_vgg9_inference(cfg, images.shape[0])
     logits, counts, stats = _infer_hybrid_fused(
-        params, images, cfg=cfg, plan=plan, interpret=interpret,
-        with_stats=return_stats)
+        params, images, cfg=cfg, plan=plan, with_stats=return_stats)
     if return_stats:
         return logits, counts, stats
     return logits, counts
@@ -327,9 +332,45 @@ def vgg9_infer_hybrid(params: Dict, images: jax.Array, cfg: VGG9Config, *,
 _SHARDED_FNS: Dict = {}
 
 
+def sharded_infer_fn(params, images, cfg: VGG9Config, *, mesh, axis: str,
+                     plan, with_stats: bool):
+    """The jitted ``shard_map`` callable behind `vgg9_infer_hybrid_sharded`
+    for this batch shape: ``fn(params, images) -> (logits, counts, stats)``.
+
+    ``params`` and ``images`` may be arrays or `jax.ShapeDtypeStruct`s (only
+    their shapes are read), so the graph can be lowered for a described
+    device mesh. Cached per (cfg, plan, mesh, axis, with_stats, shape)."""
+    from jax.sharding import PartitionSpec as P
+
+    ndev = int(mesh.shape[axis])
+    b_local = images.shape[0] // ndev
+    key = (cfg, plan, mesh, axis, with_stats, images.shape, str(images.dtype))
+    if key not in _SHARDED_FNS:
+        def local_fn(p, im):
+            logits, counts, stats = _infer_hybrid_fused(
+                p, im, cfg=cfg, plan=plan, with_stats=with_stats)
+            counts = {k: v.reshape(1) for k, v in counts.items()}
+            stats = {
+                name: {k: (v if k.endswith("_per_image") else v[None])
+                       for k, v in st.items()}
+                for name, st in stats.items()}
+            return logits, counts, stats
+
+        param_shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
+        shape_local = jax.ShapeDtypeStruct((b_local,) + images.shape[1:],
+                                           images.dtype)
+        out_shapes = jax.eval_shape(local_fn, param_shapes, shape_local)
+        out_specs = jax.tree.map(lambda _: P(axis), out_shapes)
+        _SHARDED_FNS[key] = jax.jit(jax.shard_map(
+            local_fn, mesh=mesh, in_specs=(P(), P(axis)),
+            out_specs=out_specs, check_vma=False))
+    return _SHARDED_FNS[key]
+
+
 def vgg9_infer_hybrid_sharded(params: Dict, images: jax.Array, cfg: VGG9Config, *,
-                              mesh, axis: str = "data", interpret: bool = True,
-                              plan=None, return_stats: bool = False):
+                              mesh, axis: str = "data", plan=None,
+                              return_stats: bool = False):
     """Data-mesh sharded fused inference: the folded ``[T*B·H·W, K]`` spiking
     matmuls split over ``mesh``'s ``axis`` via ``shard_map``.
 
@@ -362,45 +403,26 @@ def vgg9_infer_hybrid_sharded(params: Dict, images: jax.Array, cfg: VGG9Config, 
         from ..core.hybrid import plan_vgg9_inference
         plan = plan_vgg9_inference(cfg, b_local)
 
-    from jax.sharding import PartitionSpec as P
-
-    key = (cfg, plan, mesh, axis, interpret, return_stats,
-           images.shape, str(images.dtype))
-    if key not in _SHARDED_FNS:
-        def local_fn(p, im):
-            logits, counts, stats = _infer_hybrid_fused(
-                p, im, cfg=cfg, plan=plan, interpret=interpret,
-                with_stats=return_stats)
-            counts = {k: v.reshape(1) for k, v in counts.items()}
-            stats = {
-                name: {k: (v if k.endswith("_per_image") else v[None])
-                       for k, v in st.items()}
-                for name, st in stats.items()}
-            return logits, counts, stats
-
-        shape_local = jax.ShapeDtypeStruct((b_local,) + images.shape[1:],
-                                           images.dtype)
-        out_shapes = jax.eval_shape(local_fn, params, shape_local)
-        out_specs = jax.tree.map(lambda _: P(axis), out_shapes)
-        _SHARDED_FNS[key] = jax.jit(jax.shard_map(
-            local_fn, mesh=mesh, in_specs=(P(), P(axis)),
-            out_specs=out_specs, check_vma=False))
-    logits, counts, stats = _SHARDED_FNS[key](params, images)
+    fn = sharded_infer_fn(params, images, cfg, mesh=mesh, axis=axis,
+                          plan=plan, with_stats=return_stats)
+    logits, counts, stats = fn(params, images)
     if return_stats:
         return logits, counts, stats
     return logits, counts
 
 
-def vgg9_infer_hybrid_unfused(params: Dict, images: jax.Array, cfg: VGG9Config, *,
-                              interpret: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+def vgg9_infer_hybrid_unfused(params: Dict, images: jax.Array,
+                              cfg: VGG9Config) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The pre-fusion pipeline: T separate in-kernel-gated spike_conv +
     lif_step launches per layer from a Python loop. Kept as the benchmark
     baseline for benchmarks/hybrid_pipeline.py."""
+    from ..kernels import interpret_mode
     from ..kernels.dense_conv_lif.ops import input_layer_conv_lif
     from ..kernels.spike_conv.ops import spike_conv2d
     from ..kernels.lif_step.ops import lif_update
 
     assert cfg.coding == "direct"
+    interpret = interpret_mode()
     qp = quantized_view(params, cfg)
     b = images.shape[0]
 
@@ -434,7 +456,8 @@ def vgg9_infer_hybrid_unfused(params: Dict, images: jax.Array, cfg: VGG9Config, 
         s_prev = jnp.zeros_like(u)
         outs = []
         for t in range(cfg.timesteps):
-            cur = flat[t] @ qp[name]["w"] + qp[name]["b"]
+            cur = jnp.dot(flat[t], qp[name]["w"],
+                          precision=jax.lax.Precision.HIGHEST) + qp[name]["b"]
             u, s_prev = lif_update(u, cur, s_prev, beta=cfg.beta, theta=cfg.theta,
                                    interpret=interpret)
             outs.append(s_prev)

@@ -98,7 +98,7 @@ class VariantRegistry:
         self._warmed = True
 
 
-def make_snn_variants(cfg, params, *, interpret: bool = True) -> VariantRegistry:
+def make_snn_variants(cfg, params) -> VariantRegistry:
     """fp32 + int4 spiking-VGG9 variants over one set of raw params.
 
     The int4 variant's quantized weight view lives inside its jitted fused
@@ -111,8 +111,8 @@ def make_snn_variants(cfg, params, *, interpret: bool = True) -> VariantRegistry
 
     fp32_cfg = dataclasses.replace(cfg, quant_bits=0)
     int4_cfg = dataclasses.replace(cfg, quant_bits=4)
-    variants = {"fp32": SNNRunner(fp32_cfg, params, interpret=interpret),
-                "int4": SNNRunner(int4_cfg, params, interpret=interpret)}
+    variants = {"fp32": SNNRunner(fp32_cfg, params),
+                "int4": SNNRunner(int4_cfg, params)}
 
     def warm(reg: VariantRegistry, slots: int) -> None:
         import jax.numpy as jnp

@@ -776,11 +776,29 @@ def make_worker_fleet(spec: Any, n: int,
     and ship telemetry increments on each heartbeat; `Router.telemetry()`
     merges them — spans from all workers plus the router's own — into one
     cross-process trace.
+
+    An accelerator belongs to one process and workers are not pinned to
+    chips, so the first worker on a TPU holds every chip of the host: a
+    fleet of more than one worker there fails after the first handshake,
+    with a message saying so, instead of a later worker falling back to
+    the CPU or waiting on a chip that never frees.
     """
     from .worker import SubprocessTransport
-    transports = [SubprocessTransport(spec, config,
-                                      step_timeout_s=step_timeout_s, obs=obs)
-                  for _ in range(n)]
+
+    def spawn():
+        return SubprocessTransport(spec, config, step_timeout_s=step_timeout_s,
+                                   obs=obs)
+
+    first = spawn()
+    if n > 1 and first.platform not in ("", "cpu"):
+        first.close()
+        raise RuntimeError(
+            f"a fleet of {n} workers needs one {first.platform} chip per "
+            f"worker, but worker 0 (pid {first.pid}) holds all "
+            f"{first.devices} visible {first.platform} device(s): a chip "
+            f"belongs to one process and workers are not pinned to chips. "
+            f"Serve with one worker, or in-process.")
+    transports = [first] + [spawn() for _ in range(n - 1)]
     router_kwargs.setdefault("stall_factor", float("inf"))
     return Router(transports,
                   obs=Observability() if obs else None, **router_kwargs)

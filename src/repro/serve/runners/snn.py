@@ -31,8 +31,10 @@ from __future__ import annotations
 
 from typing import Dict, Hashable, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ...core.energy import analytical_energy_per_image, energy_per_image
 from ...core.hybrid import HybridPlan, plan_vgg9_inference
@@ -88,11 +90,11 @@ def _per_timestep_occupancy(row_occ: np.ndarray, rows: int,
 class SNNRunner:
     """Fixed-slot spiking-VGG9 serving (`ModelRunner`)."""
 
-    def __init__(self, cfg: VGG9Config, params, *, interpret: bool = True):
+    def __init__(self, cfg: VGG9Config, params):
         self.cfg = cfg
         self.params = params
-        self.interpret = interpret
         self._plans: Dict[int, HybridPlan] = {}
+        self._replicated: Dict = {}         # mesh -> params on every device
 
     def plan(self, batch: int) -> HybridPlan:
         """The inference plan for a slot count (cached: plans are static jit
@@ -121,8 +123,7 @@ class SNNRunner:
     def _run_unsharded(self, images, n: int):
         plan = self.plan(n)
         logits, counts, stats = vgg9_infer_hybrid(
-            self.params, images, self.cfg, interpret=self.interpret,
-            plan=plan, return_stats=True)
+            self.params, images, self.cfg, plan=plan, return_stats=True)
         batch_skip = {k: float(v["skip_rate"]) for k, v in stats.items()
                       if "skip_rate" in v}
         out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
@@ -161,9 +162,14 @@ class SNNRunner:
         mesh = current_mesh()
         b_local = n // ndev
         plan = self.plan(b_local)
+        if mesh not in self._replicated:
+            # place the weights on every device of the mesh once, instead of
+            # broadcasting them from the default device on every batch
+            self._replicated[mesh] = jax.device_put(
+                self.params, NamedSharding(mesh, PartitionSpec()))
         logits, counts, stats = vgg9_infer_hybrid_sharded(
-            self.params, images, self.cfg, mesh=mesh, interpret=self.interpret,
-            plan=plan, return_stats=True)
+            self._replicated[mesh], images, self.cfg, mesh=mesh, plan=plan,
+            return_stats=True)
         batch_skip = {k: float(np.mean(np.asarray(v["skip_rate"])))
                       for k, v in stats.items() if "skip_rate" in v}
         out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)

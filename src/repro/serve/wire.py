@@ -47,7 +47,9 @@ from .api import Request, Result
 #: increments piggybacking on the step reply). Both are default-valued —
 #: same-build peers always agree, and the version stamp keeps a v1 peer
 #: from half-decoding a v2 stream.
-PROTOCOL_VERSION = 2
+#: v3: `ReadyMsg.platform`/`devices` (the backend the worker computes on, so
+#: the parent can refuse a fleet that would need more chips than exist).
+PROTOCOL_VERSION = 3
 
 #: refuse frames larger than this (corrupted length prefix guard)
 MAX_FRAME_BYTES = 1 << 30
@@ -166,10 +168,16 @@ class HelloMsg:
 
 @dataclasses.dataclass(frozen=True)
 class ReadyMsg:
-    """Worker -> parent handshake close: the engine is built and serving."""
+    """Worker -> parent handshake close: the engine is built and serving.
+
+    platform / devices: the JAX backend the worker's runner computes on and
+    how many of its devices the worker sees ('' and 0 for the jax-free
+    stub runner)."""
     TYPE: ClassVar[str] = "ready"
     pid: int = 0
     workload: str = ""
+    platform: str = ""
+    devices: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

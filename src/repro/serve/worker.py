@@ -82,7 +82,6 @@ class RunnerSpec:
     seed:        `PRNGKey` seed for parameter init. Same spec -> same
                  params -> bit-identical greedy outputs in every process.
     max_seq / quant_bits / speculate_k: `runners.lm.LMRunner` knobs.
-    interpret:   run SNN kernels in interpret mode (CPU CI).
     """
     kind: str
     arch: Mapping[str, Any] = dataclasses.field(default_factory=dict)
@@ -90,7 +89,6 @@ class RunnerSpec:
     max_seq: int = 64
     quant_bits: int = 0
     speculate_k: int = 0
-    interpret: bool = True
 
     def to_wire(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -112,10 +110,9 @@ def lm_spec(cfg, *, seed: int = 0, max_seq: int = 64, quant_bits: int = 0,
                       speculate_k=speculate_k)
 
 
-def snn_spec(cfg, *, seed: int = 0, interpret: bool = True) -> RunnerSpec:
+def snn_spec(cfg, *, seed: int = 0) -> RunnerSpec:
     """Spec for an `SNNRunner` over ``cfg`` (a `VGG9Config`)."""
-    return RunnerSpec(kind="snn", arch=dataclasses.asdict(cfg), seed=seed,
-                      interpret=interpret)
+    return RunnerSpec(kind="snn", arch=dataclasses.asdict(cfg), seed=seed)
 
 
 def build_runner(spec: RunnerSpec):
@@ -142,7 +139,7 @@ def build_runner(spec: RunnerSpec):
         from .runners.snn import SNNRunner
         cfg = VGG9Config(**dict(spec.arch))
         params = init_vgg9(jax.random.PRNGKey(spec.seed), cfg)
-        return SNNRunner(cfg, params, interpret=spec.interpret)
+        return SNNRunner(cfg, params)
     raise ProtocolError(f"unknown RunnerSpec.kind {spec.kind!r} "
                         f"(known: lm, snn, stub)")
 
@@ -226,6 +223,15 @@ def _heartbeat(core: EngineCore, seq: int) -> HeartbeatMsg:
                         stats=core.stats(), telemetry=telemetry)
 
 
+def _device_view(spec: RunnerSpec) -> Tuple[str, int]:
+    """(platform, device count) of the backend a built runner computes on;
+    ('', 0) for the stub, which never imports jax."""
+    if spec.kind == "stub":
+        return "", 0
+    import jax
+    return jax.default_backend(), jax.device_count()
+
+
 def serve_connection(rfile, wfile) -> int:
     """Speak the worker side of the protocol until shutdown/EOF.
 
@@ -257,7 +263,9 @@ def serve_connection(rfile, wfile) -> int:
     except Exception as e:              # bad spec/config: refuse loudly
         send(ErrorMsg(error=f"worker build failed: {e!r}"))
         return 2
-    send(ReadyMsg(pid=os.getpid(), workload=spec.kind))
+    platform, devices = _device_view(spec)
+    send(ReadyMsg(pid=os.getpid(), workload=spec.kind, platform=platform,
+                  devices=devices))
 
     live: Set[int] = set()              # rids with no ResultMsg pushed yet
 
@@ -319,6 +327,8 @@ def serve_connection(rfile, wfile) -> int:
 
 
 def main() -> int:
+    from ..launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # Reserve the real stdout fd for protocol frames and re-point fd 1 at
     # stderr, so library prints (jax logs etc.) cannot corrupt the stream.
     proto_in = sys.stdin.buffer
@@ -406,6 +416,8 @@ class SubprocessTransport:
             self._reap()
             raise ProtocolError(self._dead)
         self.pid = reply.pid
+        self.platform = reply.platform
+        self.devices = reply.devices
 
     # -- low-level I/O -------------------------------------------------------
 

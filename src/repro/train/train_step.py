@@ -141,7 +141,6 @@ def shard_map_compressed_step(step, mesh, data_axis: str = "data"):
     constraints would conflict.
     """
     from jax.sharding import PartitionSpec as P
-    from ..dist import compat as _compat  # noqa: F401  (jax.shard_map shim)
     state_specs = {"params": P(), "opt": P(), "step": P(),
                    "grad_err": P(data_axis)}
 

@@ -102,7 +102,7 @@ def test_two_device_engine_bit_identical():
         imgs[1] = imgs[1] * 0.01     # a near-silent request: sparsity signal
 
         def serve(mesh):
-            runner = SNNRunner(cfg, params, interpret=True)
+            runner = SNNRunner(cfg, params)
             core = EngineCore(runner, EngineConfig(slots=4))
             ids = [core.submit(im) for im in imgs]
             if mesh is not None:

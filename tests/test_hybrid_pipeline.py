@@ -37,7 +37,7 @@ def setup():
 def test_fused_matches_training_path(setup, cfg):
     params, imgs = setup
     ref_logits, ref_counts = vgg9_forward(params, imgs, cfg)
-    logits, counts = vgg9_infer_hybrid(params, imgs, cfg, interpret=True)
+    logits, counts = vgg9_infer_hybrid(params, imgs, cfg)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits), atol=1e-5)
     for k in ref_counts:
         assert int(counts[k]) == int(ref_counts[k]), k
@@ -47,8 +47,8 @@ def test_fused_matches_unfused_bitexact(setup):
     """Folding T into the batch + occupancy mapping must not change numerics
     vs the per-timestep in-kernel-gated pipeline."""
     params, imgs = setup
-    a, ca = vgg9_infer_hybrid(params, imgs, CFG, interpret=True)
-    b, cb = vgg9_infer_hybrid_unfused(params, imgs, CFG, interpret=True)
+    a, ca = vgg9_infer_hybrid(params, imgs, CFG)
+    b, cb = vgg9_infer_hybrid_unfused(params, imgs, CFG)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for k in ca:
         assert int(ca[k]) == int(cb[k]), k
@@ -104,11 +104,11 @@ def test_fused_launches_once_per_spiking_layer(setup):
     jax.clear_caches()                       # force a fresh trace to count
 
     sc_ops.reset_launch_counts()
-    vgg9_infer_hybrid(params, imgs, CFG, interpret=True)
+    vgg9_infer_hybrid(params, imgs, CFG)
     assert sc_ops.launch_counts().get("spike_matmul_mapped", 0) == n_spiking
 
     sc_ops.reset_launch_counts()
-    vgg9_infer_hybrid_unfused(params, imgs, CFG, interpret=True)
+    vgg9_infer_hybrid_unfused(params, imgs, CFG)
     assert sc_ops.launch_counts().get("spike_matmul", 0) == n_spiking * CFG.timesteps
 
 
@@ -143,6 +143,6 @@ def test_fused_respects_custom_plan(setup):
         if l.kernel and l.kernel.kernel == "spike_conv_mapped" else l
         for l in plan.layers)
     big = dataclasses.replace(plan, layers=layers)
-    a, _ = vgg9_infer_hybrid(params, imgs, CFG, interpret=True, plan=big)
+    a, _ = vgg9_infer_hybrid(params, imgs, CFG, plan=big)
     ref, _ = vgg9_forward(params, imgs, CFG)
     np.testing.assert_allclose(np.asarray(a), np.asarray(ref), atol=1e-5)

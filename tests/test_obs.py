@@ -239,7 +239,7 @@ def test_snn_bit_identical_with_obs_attached(seed):
     from repro.serve.runners.snn import SNNRunner
     cfg = vgg9_snn.TINY
     params = init_vgg9(jax.random.PRNGKey(seed), cfg)
-    runner = SNNRunner(cfg, params, interpret=True)
+    runner = SNNRunner(cfg, params)
     keys = jax.random.split(jax.random.PRNGKey(seed + 10), 3)
     imgs = [jax.random.uniform(k, (cfg.img_hw, cfg.img_hw, cfg.in_ch))
             for k in keys]

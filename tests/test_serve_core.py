@@ -112,7 +112,7 @@ def test_snn_engine_matches_direct_call(snn_setup, cfg):
     results = core.run_until_complete()
 
     direct_logits, direct_counts, direct_stats = vgg9_infer_hybrid(
-        params, imgs, cfg, interpret=True, plan=runner.plan(4), return_stats=True)
+        params, imgs, cfg, plan=runner.plan(4), return_stats=True)
     direct_logits = np.asarray(direct_logits)
 
     for i, rid in enumerate(ids):
@@ -145,7 +145,7 @@ def test_snn_partial_batch_pads_with_zero_images(snn_setup):
 
     padded = jnp.concatenate([imgs[:3], jnp.zeros_like(imgs[:1])])
     direct_logits, _ = vgg9_infer_hybrid(params, padded, SNN_CFG,
-                                         interpret=True, plan=runner.plan(4))
+                                         plan=runner.plan(4))
     for i, rid in enumerate(ids):
         np.testing.assert_array_equal(np.asarray(results[rid].outputs),
                                       np.asarray(direct_logits)[i])
@@ -188,14 +188,14 @@ def test_conv0_blocks_come_from_plan_and_launch_counted(snn_setup):
 
     jax.clear_caches()
     dense_ops.reset_launch_counts()
-    a, _ = vgg9_infer_hybrid(params, imgs, SNN_CFG, interpret=True, plan=small)
+    a, _ = vgg9_infer_hybrid(params, imgs, SNN_CFG, plan=small)
     assert dense_ops.launch_counts() == {"dense_conv_lif": 1}
     assert dense_ops.LAUNCH_LOG == [{"block_m": min(ks0.block_m, 4 * 16 * 16),
                                      "block_n": 64}]
 
     jax.clear_caches()
     dense_ops.reset_launch_counts()
-    b, _ = vgg9_infer_hybrid(params, imgs, SNN_CFG, interpret=True, plan=plan)
+    b, _ = vgg9_infer_hybrid(params, imgs, SNN_CFG, plan=plan)
     assert dense_ops.LAUNCH_LOG[0]["block_n"] == min(ks0.block_n, 128)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))  # blocks don't change numerics
 

@@ -42,7 +42,7 @@ def test_hybrid_kernels_bitexact_vs_training_path(setup):
     """Dense-core + sparse-core kernel inference == pure-JAX reference."""
     params, imgs, _ = setup
     ref_logits, ref_counts = vgg9_forward(params, imgs, CFG)
-    hyb_logits, hyb_counts = vgg9_infer_hybrid(params, imgs, CFG, interpret=True)
+    hyb_logits, hyb_counts = vgg9_infer_hybrid(params, imgs, CFG)
     np.testing.assert_array_equal(np.asarray(hyb_logits), np.asarray(ref_logits))
     for k in ref_counts:
         assert int(hyb_counts[k]) == int(ref_counts[k]), k

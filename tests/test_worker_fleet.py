@@ -216,3 +216,53 @@ def test_lm_fleet_kill_replays_bit_identical():
     for rid, want, prompt in zip(rids, expected, PROMPTS):
         assert results[rid].status == "ok"
         assert list(results[rid].outputs) == list(want), prompt
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_ready_reports_the_backend_a_worker_computes_on():
+    import jax
+
+    from repro.configs import vgg9_snn
+    from repro.serve.worker import snn_spec
+
+    code, frames = drive_worker([ShutdownMsg()])
+    assert code == 0 and (frames[0].platform, frames[0].devices) == ("", 0)
+
+    inbuf = io.BytesIO()
+    write_frame(inbuf, HelloMsg(runner=snn_spec(vgg9_snn.TINY).to_wire(),
+                                config=dataclasses.asdict(CONFIG)))
+    write_frame(inbuf, ShutdownMsg())
+    inbuf.seek(0)
+    out = io.BytesIO()
+    assert serve_connection(inbuf, out) == 0
+    out.seek(0)
+    ready = read_frame(out)
+    assert ready.workload == "snn"
+    assert ready.platform == jax.default_backend()
+    assert ready.devices == jax.device_count()
+
+
+def test_fleet_larger_than_the_chips_fails_at_launch(monkeypatch):
+    """On a TPU the first worker holds every chip; a second one is refused
+    before it is spawned, and the first is reaped."""
+    spawned = []
+
+    class OnChip:
+        platform, devices, pid = "tpu", 4, 4242
+
+        def __init__(self, spec, config, **kwargs):
+            spawned.append(self)
+            self.closed = False
+
+        def close(self):
+            self.closed = True
+
+    monkeypatch.setattr("repro.serve.worker.SubprocessTransport", OnChip)
+    with pytest.raises(RuntimeError,
+                       match="fleet of 2 workers needs one tpu chip per "
+                             "worker.*holds all 4 visible tpu"):
+        make_worker_fleet(STUB, 2, CONFIG)
+    assert len(spawned) == 1 and spawned[0].closed
