@@ -36,12 +36,13 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, aggregate,
                       to_prometheus)
 from .recorder import FlightRecorder, summarize_report
+from .stages import Stages
 from .trace import Span, Tracer, merge_traces
 
 __all__ = [
     "Observability", "Tracer", "Span", "merge_traces",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "aggregate",
-    "to_prometheus", "FlightRecorder", "summarize_report",
+    "to_prometheus", "FlightRecorder", "summarize_report", "Stages",
 ]
 
 #: Result.stats keys summed into served-energy counters (both cost models)
@@ -52,14 +53,28 @@ _ENERGY_KEYS = (("served_energy_j", "precision_served_energy_eq3_j",
                  "analytical per-op served energy of retired requests (J)"))
 
 
+def publish_stages(registry: MetricsRegistry,
+                   stages: Dict[str, Dict[str, float]]) -> None:
+    """Bring the ``stage_<name>_seconds`` / ``stage_<name>_calls`` counters
+    up to an engine's cumulative ``host_stages`` (dots in a stage name
+    become underscores: ``snn.fetch`` -> ``stage_snn_fetch_seconds``)."""
+    for name, totals in stages.items():
+        key = name.replace(".", "_")
+        for field, help in (("seconds", "host seconds"), ("calls", "calls")):
+            counter = registry.counter(f"stage_{key}_{field}",
+                                       f"{help} in host stage {name}")
+            counter.inc(max(0.0, totals[field] - counter.value))
+
+
 class Observability:
     """Bundle of tracer + metrics + recorder with engine-shaped hooks.
 
     Any pillar can be disabled (``trace=False``, ``metrics=False``,
     ``recorder=0``); hooks skip the missing pieces. ``attach_engine``
     registers pull collectors for the scheduler's and precision
-    controller's ``metrics_into`` and remembers the controller so its
-    per-request decisions land in the recorder's notes.
+    controller's ``metrics_into`` and the engine's host-stage totals, and
+    remembers the controller so its per-request decisions land in the
+    recorder's notes.
     """
 
     def __init__(self, *, trace: bool = True, metrics: bool = True,
@@ -84,6 +99,10 @@ class Observability:
             if callable(publish):
                 self.metrics.collectors.append(
                     lambda reg, _p=publish: _p(reg))
+            host_stages = getattr(core, "host_stages", None)
+            if callable(host_stages):
+                self.metrics.collectors.append(
+                    lambda reg, _s=host_stages: publish_stages(reg, _s()))
         controller = getattr(getattr(core, "runner", None), "controller", None)
         if controller is not None:
             self._controller = controller

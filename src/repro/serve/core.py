@@ -68,6 +68,7 @@ from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.stages import Stages
 from .api import (EngineConfig, EngineStalled, ModelRunner, QueueFull,
                   Request, Result, RunnerSession, SlotProgress, StepBudget,
                   SubmitSpec)
@@ -199,6 +200,9 @@ class EngineCore:
         #: the last `StepReport` a continuous-admission step produced —
         #: supervision surface for `serve.router.Router`'s health probes.
         self.last_report: Optional[Any] = None
+        #: host time per stage of a step (``engine.*`` spans), on the
+        #: profiler's clock; `stats()` exports it with the runner's.
+        self.stages = Stages()
         #: optional `repro.obs.Observability` bundle. Hooks only receive
         #: values the engine computed anyway (clock readings, reports,
         #: results) — attaching one is bit-identical to running without
@@ -344,9 +348,10 @@ class EngineCore:
         continuous: refill freed slots from the queue, then run one session
         iteration. batch: form and run one batch to completion.
         """
-        if self.config.admission == "batch":
-            return self._step_batch()
-        return self._step_continuous()
+        with self.stages.span("engine.step", step_num=self._steps_run):
+            if self.config.admission == "batch":
+                return self._step_batch()
+            return self._step_continuous()
 
     def _progress_marker(self) -> Tuple[int, int, int, int]:
         """Anything that changes between steps when the engine is healthy:
@@ -418,8 +423,28 @@ class EngineCore:
     # -- continuous admission ------------------------------------------------
 
     def _step_continuous(self) -> int:
+        with self.stages.span("engine.admit"):
+            now = self._clock()
+            done = self._admit_continuous(now)
+        occupied = [s for s in self.slots if s.request_id is not None]
+        if not occupied:
+            return done
+
+        budget = StepBudget(chunk=self.config.prefill_chunk)
+        plan = getattr(self.scheduler, "plan_step", None)
+        if plan is not None:
+            residents = {s.index: self._resident[s.request_id] for s in occupied}
+            budget = plan(residents, dict(self._progress), now=now,
+                          default=budget)
+        t0 = self._clock()
+        report = self._session.step(budget)
+        with self.stages.span("engine.retire"):
+            return done + self._retire_step(report, occupied, t0)
+
+    def _admit_continuous(self, now: float) -> int:
+        """Retire expired requests, then refill freed slots from the queue;
+        returns how many admitted requests completed at once (0 work)."""
         done = 0
-        now = self._clock()
         tick = getattr(self.scheduler, "on_clock", None)
         if tick is not None:        # select()'s signature carries no clock
             tick(now)
@@ -465,19 +490,12 @@ class EngineCore:
                     "scheduler admitted nothing into an idle engine with a "
                     "non-empty queue (Scheduler.select contract: with "
                     "active_key=None it must pick at least one request)")
+        return done
 
-        occupied = [s for s in self.slots if s.request_id is not None]
-        if not occupied:
-            return done
-
-        budget = StepBudget(chunk=self.config.prefill_chunk)
-        plan = getattr(self.scheduler, "plan_step", None)
-        if plan is not None:
-            residents = {s.index: self._resident[s.request_id] for s in occupied}
-            budget = plan(residents, dict(self._progress), now=now,
-                          default=budget)
-        t0 = self._clock()
-        report = self._session.step(budget)
+    def _retire_step(self, report, occupied: List[_Slot], t0: float) -> int:
+        """Account for one session step, screen its outputs and route its
+        partials and finished results; returns #requests completed."""
+        done = 0
         self._steps_run += 1          # before the clock read: a step-counting
         self._batches_run += 1        # clock must see this step as elapsed
         seconds = self._clock() - t0
@@ -550,25 +568,26 @@ class EngineCore:
     def _step_batch(self) -> int:
         if not self._queue:
             return 0
-        picks = self.scheduler.select(
-            tuple(self._queue), self.config.slots,
-            key_fn=self.runner.bucket_key, active_key=None)
-        assert picks, "Scheduler.select returned nothing for an idle engine"
-        self._take_from_queue(picks, self.runner.bucket_key)
-        self.admission_log.append(
-            (self._steps_run, [r.request_id for r in picks]))
-        if self.obs is not None:
-            self.obs.on_admit([r.request_id for r in picks],
-                              self._steps_run, self._clock())
+        with self.stages.span("engine.admit"):
+            picks = self.scheduler.select(
+                tuple(self._queue), self.config.slots,
+                key_fn=self.runner.bucket_key, active_key=None)
+            assert picks, "Scheduler.select returned nothing for an idle engine"
+            self._take_from_queue(picks, self.runner.bucket_key)
+            self.admission_log.append(
+                (self._steps_run, [r.request_id for r in picks]))
+            if self.obs is not None:
+                self.obs.on_admit([r.request_id for r in picks],
+                                  self._steps_run, self._clock())
 
-        batch: List[Request] = list(picks)
-        for slot, req in zip(self.slots, batch):
-            slot.acquire(req.request_id)
-            self._resident[req.request_id] = req
-            self.scheduler.on_admit(req)
-        # pad to the full slot count: the runner always sees static shapes
-        while len(batch) < self.config.slots:
-            batch.append(self.runner.filler(batch[0]))
+            batch: List[Request] = list(picks)
+            for slot, req in zip(self.slots, batch):
+                slot.acquire(req.request_id)
+                self._resident[req.request_id] = req
+                self.scheduler.on_admit(req)
+            # pad to the full slot count: the runner always sees static shapes
+            while len(batch) < self.config.slots:
+                batch.append(self.runner.filler(batch[0]))
 
         results = self.runner.run(batch)
         assert len(results) == self.config.slots, (
@@ -634,4 +653,16 @@ class EngineCore:
                             if self._drafted_tokens else 0.0),
             "goodput_accepted_tok_per_step": (self._accepted_tokens / steps
                                               if steps else 0.0),
+            # host seconds, calls and longest call per stage of a step: the
+            # engine's ``engine.*`` spans and the runner's (``snn.*``)
+            "host_stages": self.host_stages(),
         }
+
+    def host_stages(self) -> Dict[str, Dict[str, float]]:
+        """``{stage: {"seconds", "calls", "max_s"}}`` of the engine's spans
+        and, where the runner keeps an `obs.stages.Stages`, the runner's."""
+        out = self.stages.snapshot()
+        runner_stages = getattr(self.runner, "stages", None)
+        if runner_stages is not None:
+            out.update(runner_stages.snapshot())
+        return out

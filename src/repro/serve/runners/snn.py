@@ -42,6 +42,7 @@ from ...core.workload import (conv_workload, dense_input_workload, fc_workload)
 from ...dist.context import current_mesh
 from ...models.vgg9 import (VGG9Config, conv_names, vgg9_infer_hybrid,
                             vgg9_infer_hybrid_sharded)
+from ...obs.stages import Stages
 from ..api import (PAD_REQUEST_ID, Request, Result, SlotProgress, StepBudget,
                    StepReport)
 
@@ -95,6 +96,8 @@ class SNNRunner:
         self.params = params
         self._plans: Dict[int, HybridPlan] = {}
         self._replicated: Dict = {}         # mesh -> params on every device
+        #: host time per stage of `run` (``snn.*`` spans, once per call)
+        self.stages = Stages()
 
     def plan(self, batch: int) -> HybridPlan:
         """The inference plan for a slot count (cached: plans are static jit
@@ -120,33 +123,43 @@ class SNNRunner:
         ndev = int(mesh.shape["data"])
         return ndev if ndev > 1 and n % ndev == 0 else 1
 
+    def _call(self, fn, *args, **kwargs):
+        """Dispatch the fused graph, then wait for the device: two spans, so
+        the enqueue (and any recompile) and the wait on the chip read apart."""
+        with self.stages.span("snn.dispatch"):
+            out = fn(*args, **kwargs)
+        with self.stages.span("snn.device_wait"):
+            return jax.block_until_ready(out)
+
     def _run_unsharded(self, images, n: int):
         plan = self.plan(n)
-        logits, counts, stats = vgg9_infer_hybrid(
-            self.params, images, self.cfg, plan=plan, return_stats=True)
-        batch_skip = {k: float(v["skip_rate"]) for k, v in stats.items()
-                      if "skip_rate" in v}
-        out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
-                      for k, v in stats.items()}
-        in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
-                     for k, v in stats.items() if "in_spikes_per_image" in v}
+        logits, _, stats = self._call(
+            vgg9_infer_hybrid, self.params, images, self.cfg, plan=plan,
+            return_stats=True)
+        with self.stages.span("snn.fetch"):
+            logits = np.asarray(logits)
+            batch_skip = {k: float(v["skip_rate"]) for k, v in stats.items()
+                          if "skip_rate" in v}
+            out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
+                          for k, v in stats.items()}
+            in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
+                         for k, v in stats.items() if "in_spikes_per_image" in v}
+            occ = {name: (np.asarray(st["row_occ"]), int(st["block_m"]),
+                          int(st["rows"]))
+                   for name, st in stats.items() if "occ_map" in st}
 
-        per_req_skip: Dict[str, np.ndarray] = {}
-        ts_occ: Dict[str, np.ndarray] = {}
-        for name, st in stats.items():
-            if "occ_map" not in st:
-                continue
-            ks = plan.layer(name).kernel
+        with self.stages.span("snn.split"):
+            per_req_skip: Dict[str, np.ndarray] = {}
+            ts_occ: Dict[str, np.ndarray] = {}
             t = self.cfg.timesteps
-            rps = ks.m // (t * n)
-            row_occ = np.asarray(st["row_occ"])
-            per_req_skip[name] = _per_request_skip(
-                row_occ, int(st["block_m"]), int(st["rows"]),
-                rows_per_slice=rps, batch=n)
-            ts_occ[name] = _per_timestep_occupancy(
-                row_occ, int(st["rows"]), rows_per_slice=rps, batch=n)
-        return (np.asarray(logits), batch_skip, out_spikes, in_spikes,
-                per_req_skip, ts_occ)
+            for name, (row_occ, block_m, rows) in occ.items():
+                rps = plan.layer(name).kernel.m // (t * n)
+                per_req_skip[name] = _per_request_skip(
+                    row_occ, block_m, rows, rows_per_slice=rps, batch=n)
+                ts_occ[name] = _per_timestep_occupancy(
+                    row_occ, rows, rows_per_slice=rps, batch=n)
+        return (logits, batch_skip, out_spikes, in_spikes, per_req_skip,
+                ts_occ)
 
     def _run_sharded(self, images, n: int, ndev: int):
         """Split the slot batch over the data mesh (`vgg9_infer_hybrid_sharded`)
@@ -167,42 +180,45 @@ class SNNRunner:
             # broadcasting them from the default device on every batch
             self._replicated[mesh] = jax.device_put(
                 self.params, NamedSharding(mesh, PartitionSpec()))
-        logits, counts, stats = vgg9_infer_hybrid_sharded(
-            self._replicated[mesh], images, self.cfg, mesh=mesh, plan=plan,
-            return_stats=True)
-        batch_skip = {k: float(np.mean(np.asarray(v["skip_rate"])))
-                      for k, v in stats.items() if "skip_rate" in v}
-        out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
-                      for k, v in stats.items()}
-        in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
-                     for k, v in stats.items() if "in_spikes_per_image" in v}
+        logits, _, stats = self._call(
+            vgg9_infer_hybrid_sharded, self._replicated[mesh], images,
+            self.cfg, mesh=mesh, plan=plan, return_stats=True)
+        with self.stages.span("snn.fetch"):
+            logits = np.asarray(logits)
+            batch_skip = {k: float(np.mean(np.asarray(v["skip_rate"])))
+                          for k, v in stats.items() if "skip_rate" in v}
+            out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
+                          for k, v in stats.items()}
+            in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
+                         for k, v in stats.items() if "in_spikes_per_image" in v}
+            occ = {name: (np.asarray(st["row_occ"]), np.asarray(st["block_m"]),
+                          np.asarray(st["rows"]))
+                   for name, st in stats.items() if "occ_map" in st}
 
-        per_req_skip: Dict[str, np.ndarray] = {}
-        ts_occ: Dict[str, np.ndarray] = {}
-        t = self.cfg.timesteps
-        for name, st in stats.items():
-            if "occ_map" not in st:
-                continue
-            ks = plan.layer(name).kernel
-            rps = ks.m // (t * b_local)
-            row_occ = np.asarray(st["row_occ"])
-            skip = np.zeros(n)
-            occ_t = np.zeros((t, n))
-            for d in range(ndev):
-                sl = slice(d * b_local, (d + 1) * b_local)
-                rows_d = int(np.asarray(st["rows"])[d])
-                skip[sl] = _per_request_skip(
-                    row_occ[d], int(np.asarray(st["block_m"])[d]), rows_d,
-                    rows_per_slice=rps, batch=b_local)
-                occ_t[:, sl] = _per_timestep_occupancy(
-                    row_occ[d], rows_d, rows_per_slice=rps, batch=b_local)
-            per_req_skip[name] = skip
-            ts_occ[name] = occ_t
-        return (np.asarray(logits), batch_skip, out_spikes, in_spikes,
-                per_req_skip, ts_occ)
+        with self.stages.span("snn.split"):
+            per_req_skip: Dict[str, np.ndarray] = {}
+            ts_occ: Dict[str, np.ndarray] = {}
+            t = self.cfg.timesteps
+            for name, (row_occ, block_m, rows) in occ.items():
+                rps = plan.layer(name).kernel.m // (t * b_local)
+                skip = np.zeros(n)
+                occ_t = np.zeros((t, n))
+                for d in range(ndev):
+                    sl = slice(d * b_local, (d + 1) * b_local)
+                    skip[sl] = _per_request_skip(
+                        row_occ[d], int(block_m[d]), int(rows[d]),
+                        rows_per_slice=rps, batch=b_local)
+                    occ_t[:, sl] = _per_timestep_occupancy(
+                        row_occ[d], int(rows[d]), rows_per_slice=rps,
+                        batch=b_local)
+                per_req_skip[name] = skip
+                ts_occ[name] = occ_t
+        return (logits, batch_skip, out_spikes, in_spikes, per_req_skip,
+                ts_occ)
 
     def run(self, batch: Sequence[Request]) -> List[Result]:
-        images = jnp.stack([jnp.asarray(r.payload) for r in batch])
+        with self.stages.span("snn.input"):
+            images = jnp.stack([jnp.asarray(r.payload) for r in batch])
         n = len(batch)
         ndev = self._data_shards(n)
         if ndev > 1:
@@ -213,20 +229,21 @@ class SNNRunner:
                 self._run_unsharded(images, n)
 
         # energy is priced with the full-slot-count plan in both modes so a
-        # request's Eq. 3 estimate doesn't change with the device count
-        plan = self.plan(n)
-        energies = [self._energy_estimate(plan, {k: v[i] for k, v in in_spikes.items()})
-                    for i in range(n)]
-
-        # batch-context cost: Eq. 3 priced on the batch's *total* measured
-        # spikes (pad slots are zero images and contribute nothing). A
-        # request's served_energy_j — its share of the batch it actually rode
-        # in — is what a sparsity-aware scheduler improves for sparse
-        # requests: co-batched with dense stragglers, the batch total (and
-        # therefore the share) is dominated by the straggler's spikes.
+        # request's Eq. 3 estimate doesn't change with the device count.
+        # The batch-context cost is Eq. 3 priced on the batch's *total*
+        # measured spikes (pad slots are zero images and contribute
+        # nothing). A request's served_energy_j — its share of the batch it
+        # actually rode in — is what a sparsity-aware scheduler improves for
+        # sparse requests: co-batched with dense stragglers, the batch total
+        # (and therefore the share) is dominated by the straggler's spikes.
         n_real = sum(1 for r in batch if not r.is_pad) or 1
-        batch_est = self._energy_estimate(
-            plan, {k: float(v.sum()) for k, v in in_spikes.items()})
+        with self.stages.span("snn.energy"):
+            plan = self.plan(n)
+            energies = [self._energy_estimate(
+                            plan, {k: v[i] for k, v in in_spikes.items()})
+                        for i in range(n)]
+            batch_est = self._energy_estimate(
+                plan, {k: float(v.sum()) for k, v in in_spikes.items()})
         batch_stats = {
             "batch_energy_j": batch_est["energy_j"],
             "batch_latency_s": batch_est["latency_s"],
