@@ -53,17 +53,28 @@ _ENERGY_KEYS = (("served_energy_j", "precision_served_energy_eq3_j",
                  "analytical per-op served energy of retired requests (J)"))
 
 
+#: help text of the ``stage_<name>_<total>`` counters; a total not named
+#: here (a count a stage adds) is published under its own name
+_STAGE_HELP = {"seconds": "host seconds", "calls": "calls",
+               "waits": "blocking host waits", "arrays": "device arrays read",
+               "bytes": "bytes read from the device"}
+
+
 def publish_stages(registry: MetricsRegistry,
                    stages: Dict[str, Dict[str, float]]) -> None:
-    """Bring the ``stage_<name>_seconds`` / ``stage_<name>_calls`` counters
-    up to an engine's cumulative ``host_stages`` (dots in a stage name
+    """Bring the ``stage_<name>_<total>`` counters up to an engine's
+    cumulative ``host_stages``: ``seconds``, ``calls`` and any counts the
+    stage adds, never the non-cumulative ``max_s`` (dots in a stage name
     become underscores: ``snn.fetch`` -> ``stage_snn_fetch_seconds``)."""
     for name, totals in stages.items():
         key = name.replace(".", "_")
-        for field, help in (("seconds", "host seconds"), ("calls", "calls")):
+        for field, value in totals.items():
+            if field == "max_s":
+                continue
+            help = _STAGE_HELP.get(field, field)
             counter = registry.counter(f"stage_{key}_{field}",
                                        f"{help} in host stage {name}")
-            counter.inc(max(0.0, totals[field] - counter.value))
+            counter.inc(max(0.0, value - counter.value))
 
 
 class Observability:

@@ -10,11 +10,15 @@ of a step in its spans (``engine.step``, ``engine.admit``, ``snn.input``,
 * it adds its `time.perf_counter` duration to the stage's cumulative
   ``seconds``, ``calls`` and ``max_s``.
 
+A stage may also keep counts of the work it did (`Stages.add`): the
+``snn.fetch`` stage counts its blocking host waits, the device arrays it
+brought to the host and their bytes.
+
 It is always on, and it reads only the host's performance counter: never
 the engine clock, an RNG or a `Result`, so served results are the same
 with or without anyone reading the totals. `EngineCore.stats()` exports
 them under ``host_stages``; `Observability.attach_engine` publishes them
-as ``stage_<name>_seconds`` / ``stage_<name>_calls`` counters.
+as ``stage_<name>_<total>`` counters (every total but ``max_s``).
 """
 from __future__ import annotations
 
@@ -52,10 +56,12 @@ class _Span:
 
 
 class Stages:
-    """Cumulative seconds, calls and longest call per named host stage."""
+    """Cumulative seconds, calls and longest call per named host stage, and
+    any counts a stage adds."""
 
     def __init__(self):
         self._totals: Dict[str, List[float]] = {}   # name -> [s, calls, max_s]
+        self._counts: Dict[str, Dict[str, int]] = {}  # name -> {count: total}
 
     def span(self, name: str, step_num: Optional[int] = None) -> _Span:
         """A context manager timing one call of stage ``name``; with
@@ -70,8 +76,16 @@ class Stages:
             annotation = jax.profiler.StepTraceAnnotation(name, step_num=step_num)
         return _Span(totals, annotation)
 
+    def add(self, name: str, **counts: int) -> None:
+        """Add ``counts`` to stage ``name``'s cumulative totals of the same
+        names (``add("snn.fetch", waits=1, arrays=42, bytes=...)``)."""
+        totals = self._counts.setdefault(name, {})
+        for key, n in counts.items():
+            totals[key] = totals.get(key, 0) + n
+
     def snapshot(self) -> Dict[str, Dict[str, float]]:
-        """``{name: {"seconds", "calls", "max_s"}}`` of the stages entered
-        so far, JSON-able."""
-        return {name: {"seconds": s, "calls": calls, "max_s": max_s}
+        """``{name: {"seconds", "calls", "max_s", *counts}}`` of the stages
+        entered so far, JSON-able."""
+        return {name: {"seconds": s, "calls": calls, "max_s": max_s,
+                       **self._counts.get(name, {})}
                 for name, (s, calls, max_s) in self._totals.items()}

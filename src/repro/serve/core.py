@@ -659,8 +659,9 @@ class EngineCore:
         }
 
     def host_stages(self) -> Dict[str, Dict[str, float]]:
-        """``{stage: {"seconds", "calls", "max_s"}}`` of the engine's spans
-        and, where the runner keeps an `obs.stages.Stages`, the runner's."""
+        """``{stage: {"seconds", "calls", "max_s", *counts}}`` of the
+        engine's spans and, where the runner keeps an `obs.stages.Stages`,
+        the runner's (``snn.fetch`` adds ``waits``, ``arrays``, ``bytes``)."""
         out = self.stages.snapshot()
         runner_stages = getattr(self.runner, "stages", None)
         if runner_stages is not None:

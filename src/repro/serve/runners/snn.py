@@ -88,6 +88,19 @@ def _per_timestep_occupancy(row_occ: np.ndarray, rows: int,
     return active.reshape(t, batch, rows_per_slice).mean(axis=2)
 
 
+#: the stat leaves `SNNRunner` reads back per layer of the fused graph
+_FETCHED = ("skip_rate", "out_spikes_per_image", "in_spikes_per_image",
+            "row_occ", "block_m", "rows")
+
+
+def _fetched(logits, stats):
+    """What the runner reads back of one fused-graph call: the logits and,
+    per layer in the graph's order, the stat leaves named in `_FETCHED`
+    (``occ_map`` and the tile counts stay on the device)."""
+    return logits, {name: {f: st[f] for f in _FETCHED if f in st}
+                    for name, st in stats.items()}
+
+
 class SNNRunner:
     """Fixed-slot spiking-VGG9 serving (`ModelRunner`)."""
 
@@ -124,29 +137,53 @@ class SNNRunner:
         return ndev if ndev > 1 and n % ndev == 0 else 1
 
     def _call(self, fn, *args, **kwargs):
-        """Dispatch the fused graph, then wait for the device: two spans, so
-        the enqueue (and any recompile) and the wait on the chip read apart."""
+        """Dispatch the fused graph and start the host copies of what the
+        runner reads of it (`_fetched`), then wait for the device: two
+        spans, so the enqueue (and any recompile) and the wait on the chip
+        read apart. The copies queue behind the graph; `_fetch` collects
+        them. Returns the fetched tree, still on the device."""
         with self.stages.span("snn.dispatch"):
-            out = fn(*args, **kwargs)
+            logits, _, stats = fn(*args, **kwargs)
+            fetched = _fetched(logits, stats)
+            for leaf in jax.tree_util.tree_leaves(fetched):
+                leaf.copy_to_host_async()
         with self.stages.span("snn.device_wait"):
-            return jax.block_until_ready(out)
+            return jax.block_until_ready(fetched)
+
+    def _fetch(self, fetched):
+        """Bring `_call`'s fetched tree to the host in one `jax.device_get`
+        (one blocking wait a step, not one per array), and count the wait,
+        the arrays and their bytes in the ``snn.fetch`` stage.
+
+        Returns numpy ``logits`` and, in the graph's layer order, ``skip``
+        ({layer: skip_rate}), ``out_spikes`` and ``in_spikes`` ({layer:
+        float64 [B]}) and ``occ`` ({layer: (row_occ, block_m, rows)}) of the
+        mapped layers; the sharded graph's ``[ndev]`` leaves keep that
+        axis."""
+        leaves = jax.tree_util.tree_leaves(fetched)
+        logits, host = jax.device_get(fetched)
+        self.stages.add("snn.fetch", waits=1, arrays=len(leaves),
+                        bytes=sum(leaf.nbytes for leaf in leaves))
+        layers = [(name, host[name]) for name in fetched[1]]
+        skip = {k: v["skip_rate"] for k, v in layers if "skip_rate" in v}
+        out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
+                      for k, v in layers}
+        in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
+                     for k, v in layers if "in_spikes_per_image" in v}
+        occ = {k: (v["row_occ"], v["block_m"], v["rows"])
+               for k, v in layers if "row_occ" in v}
+        return logits, skip, out_spikes, in_spikes, occ
 
     def _run_unsharded(self, images, n: int):
         plan = self.plan(n)
-        logits, _, stats = self._call(
+        fetched = self._call(
             vgg9_infer_hybrid, self.params, images, self.cfg, plan=plan,
             return_stats=True)
         with self.stages.span("snn.fetch"):
-            logits = np.asarray(logits)
-            batch_skip = {k: float(v["skip_rate"]) for k, v in stats.items()
-                          if "skip_rate" in v}
-            out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
-                          for k, v in stats.items()}
-            in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
-                         for k, v in stats.items() if "in_spikes_per_image" in v}
-            occ = {name: (np.asarray(st["row_occ"]), int(st["block_m"]),
-                          int(st["rows"]))
-                   for name, st in stats.items() if "occ_map" in st}
+            logits, skip, out_spikes, in_spikes, occ = self._fetch(fetched)
+            batch_skip = {k: float(v) for k, v in skip.items()}
+            occ = {name: (row_occ, int(block_m), int(rows))
+                   for name, (row_occ, block_m, rows) in occ.items()}
 
         with self.stages.span("snn.split"):
             per_req_skip: Dict[str, np.ndarray] = {}
@@ -180,20 +217,12 @@ class SNNRunner:
             # broadcasting them from the default device on every batch
             self._replicated[mesh] = jax.device_put(
                 self.params, NamedSharding(mesh, PartitionSpec()))
-        logits, _, stats = self._call(
+        fetched = self._call(
             vgg9_infer_hybrid_sharded, self._replicated[mesh], images,
             self.cfg, mesh=mesh, plan=plan, return_stats=True)
         with self.stages.span("snn.fetch"):
-            logits = np.asarray(logits)
-            batch_skip = {k: float(np.mean(np.asarray(v["skip_rate"])))
-                          for k, v in stats.items() if "skip_rate" in v}
-            out_spikes = {k: np.asarray(v["out_spikes_per_image"], np.float64)
-                          for k, v in stats.items()}
-            in_spikes = {k: np.asarray(v["in_spikes_per_image"], np.float64)
-                         for k, v in stats.items() if "in_spikes_per_image" in v}
-            occ = {name: (np.asarray(st["row_occ"]), np.asarray(st["block_m"]),
-                          np.asarray(st["rows"]))
-                   for name, st in stats.items() if "occ_map" in st}
+            logits, skip, out_spikes, in_spikes, occ = self._fetch(fetched)
+            batch_skip = {k: float(np.mean(v)) for k, v in skip.items()}
 
         with self.stages.span("snn.split"):
             per_req_skip: Dict[str, np.ndarray] = {}

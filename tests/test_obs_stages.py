@@ -33,6 +33,21 @@ def test_stages_accumulate_seconds_calls_and_max_for_nested_spans(monkeypatch):
     assert json.loads(json.dumps(snap)) == snap
 
 
+def test_stage_counts_add_up_beside_the_span_totals():
+    st = Stages()
+    with st.span("fetch"):
+        st.add("fetch", waits=1, arrays=42, bytes=1000)
+    with st.span("fetch"):
+        st.add("fetch", waits=1, arrays=42, bytes=1000)
+    with st.span("other"):
+        pass
+    snap = st.snapshot()
+    assert snap["fetch"]["calls"] == 2
+    assert {k: snap["fetch"][k] for k in ("waits", "arrays", "bytes")} == \
+        {"waits": 2, "arrays": 84, "bytes": 2000}
+    assert set(snap["other"]) == {"seconds", "calls", "max_s"}
+
+
 def test_a_span_records_its_call_when_the_body_raises():
     st = Stages()
     with pytest.raises(ValueError):
@@ -102,6 +117,17 @@ def test_stage_counters_reach_the_metrics_registry(snn_engine):
         assert snap[f"stage_{key}_seconds"]["value"] == pytest.approx(
             totals["seconds"])
         assert snap[f"stage_{key}_calls"]["value"] == totals["calls"]
+    # the fetch's counts: one wait a step, and the arrays and bytes it read
+    fetch = stages["snn.fetch"]
+    for field in ("waits", "arrays", "bytes"):
+        assert snap[f"stage_snn_fetch_{field}"]["kind"] == "counter"
+        assert snap[f"stage_snn_fetch_{field}"]["value"] == fetch[field]
+    assert snap["stage_snn_fetch_waits"]["value"] == 3
+    assert snap["stage_snn_fetch_arrays"]["value"] > 3
+    # the longest call is no running total, so it is no counter
+    assert not any(name.endswith("_max_s") for name in snap)
     # a second snapshot with no new work leaves the counters where they are
-    assert obs.metrics.snapshot()["stage_snn_fetch_calls"]["value"] == 3
+    again = obs.metrics.snapshot()
+    assert again["stage_snn_fetch_calls"]["value"] == 3
+    assert again["stage_snn_fetch_bytes"]["value"] == fetch["bytes"]
     assert "# TYPE stage_snn_split_seconds counter" in to_prometheus(snap)
