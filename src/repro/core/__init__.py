@@ -1,7 +1,7 @@
 """Core library: the paper's contribution as composable JAX modules."""
 from .lif import LIFParams, lif_scan, lif_step, spike_surrogate, leaky_integrate
-from .coding import direct_code, rate_code, spike_count, sparsity
-from .quant import QTensor, fake_quant, quantize_int4, dequantize, pack_int4, unpack_int4, qat_params
+from .coding import direct_code, rate_code_images, spike_count, sparsity
+from .quant import QTensor, fake_quant, quant_levels, quantize_int4, dequantize, pack_int4, unpack_int4, qat_params
 from .sparsity import SpikeStats, tile_occupancy
 from .workload import (
     LayerWorkload,

@@ -8,7 +8,10 @@ can be hoisted out of the timestep loop (computed once, reused T times) — the
 optimized hybrid path does this; the faithful path recomputes per timestep.
 
 Rate coding: each pixel intensity p in [0,1] becomes an independent Bernoulli
-spike train with rate p (one draw per timestep).
+spike train with rate p (one draw per timestep). `rate_code_images` draws an
+image's trains from a key that is a function of a base key and of the image's
+own float32 bits (`image_hash`), so a served image gets the same spikes
+whatever slot it sits in and whatever shares its batch.
 """
 from __future__ import annotations
 
@@ -21,14 +24,44 @@ def direct_code(x: jax.Array, num_steps: int) -> jax.Array:
     return jnp.broadcast_to(x[None], (num_steps,) + x.shape)
 
 
-def rate_code(key: jax.Array, x: jax.Array, num_steps: int) -> jax.Array:
-    """Bernoulli spike trains with per-pixel rate x (clipped to [0,1]).
+def _mix32(h: jax.Array) -> jax.Array:
+    """MurmurHash3's 32-bit finalizer: a bijection of uint32 words."""
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
 
-    Returns binary [T, B, ...] in x.dtype.
+
+def image_hash(images: jax.Array) -> jax.Array:
+    """[B, ...] -> [B] uint32: a hash of each image's float32 bits.
+
+    Word i of an image contributes ``mix32(bits_i ^ mix32(i))``; the
+    contributions are summed modulo 2**32. Integer arithmetic only, so the
+    hash is exact and the same on every backend and in any summation order.
     """
-    p = jnp.clip(x, 0.0, 1.0)
-    u = jax.random.uniform(key, (num_steps,) + x.shape, dtype=jnp.float32)
-    return (u < p[None].astype(jnp.float32)).astype(x.dtype)
+    b = images.shape[0]
+    bits = jax.lax.bitcast_convert_type(
+        images.astype(jnp.float32), jnp.uint32).reshape(b, -1)
+    pos = _mix32(jnp.arange(bits.shape[1], dtype=jnp.uint32))
+    return jnp.sum(_mix32(bits ^ pos), axis=1, dtype=jnp.uint32)
+
+
+def rate_code_images(key: jax.Array, images: jax.Array,
+                     num_steps: int) -> jax.Array:
+    """Per-image Bernoulli spike trains: [B, ...] -> binary [T, B, ...] float32.
+
+    Image b's spikes are ``uniform(fold_in(key, image_hash(b)), (T,) +
+    image shape) < clip(image, 0, 1)``: one draw per pixel per timestep from
+    a key of the image's own, never of its slot or its batch-mates. Equal
+    images get equal spike trains.
+    """
+    def one(h, x):
+        u = jax.random.uniform(jax.random.fold_in(key, h),
+                               (num_steps,) + x.shape, dtype=jnp.float32)
+        return (u < jnp.clip(x, 0.0, 1.0).astype(jnp.float32)).astype(jnp.float32)
+
+    return jnp.moveaxis(jax.vmap(one)(image_hash(images), images), 0, 1)
 
 
 def spike_count(spikes: jax.Array) -> jax.Array:

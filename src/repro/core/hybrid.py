@@ -3,7 +3,8 @@
 The planner is the software analogue of the paper's architecture overview:
 the direct-coded input layer (dense, non-binary activations) goes to the
 dense path; every later layer (binary spike activations) goes to the sparse,
-event-driven path. Core counts per layer come from the Eq. 3 workload model;
+event-driven path, and so does a rate-coded net's input layer, whose input
+is binary spikes too. Core counts per layer come from the Eq. 3 workload model;
 `perf^k` configurations scale the lightweight allocation by k.
 
 On TPU the "paths" select kernels: dense path -> kernels/dense_conv_lif
@@ -170,35 +171,38 @@ def plan_vgg9_inference(cfg, batch: int, *, est_density: float = 0.1,
             perf^4 scaled allocations.
 
     Returns:
-        A frozen, hashable `HybridPlan`: one `LayerPlan` per layer (conv0 on
-        the dense path with ``gate=False``; later convs on the sparse path
-        with M tiled at 128 for finest skip granularity; fc layers folded to
-        M = T*B), each carrying its `KernelSpec` launch configuration and
-        FPGA-model core count, plus the paper-style per-layer latency
-        overhead shares.
+        A frozen, hashable `HybridPlan`: one `LayerPlan` per layer (a
+        direct-coded conv0 on the dense path with ``gate=False``, a
+        rate-coded one on the sparse path over its T*B*H*W input-spike rows;
+        later convs on the sparse path with M tiled at 128 for finest skip
+        granularity; fc layers folded to M = T*B), each carrying its
+        `KernelSpec` launch configuration and FPGA-model core count, plus
+        the paper-style per-layer latency overhead shares.
     """
     t = cfg.timesteps
     convs = cfg.conv_channels
+    rate = cfg.coding == "rate"
     specs: list[dict] = []
     spike_counts: Dict[str, float] = {}
 
     hw = cfg.img_hw
-    m0, k0, n0 = batch * hw * hw, 9 * cfg.in_ch, convs[0]
-    specs.append({
-        "name": "conv0", "kind": "dense_input", "h_out": hw, "w_out": hw,
-        "c_out": convs[0], "timesteps": t,
-        "kernel": KernelSpec("dense_conv_lif", m0, k0, n0,
-                             *select_blocks(m0, k0, n0), gate=False),
-    })
+    if not rate:
+        m0, k0, n0 = batch * hw * hw, 9 * cfg.in_ch, convs[0]
+        specs.append({
+            "name": "conv0", "kind": "dense_input", "h_out": hw, "w_out": hw,
+            "c_out": convs[0], "timesteps": t,
+            "kernel": KernelSpec("dense_conv_lif", m0, k0, n0,
+                                 *select_blocks(m0, k0, n0), gate=False),
+        })
 
     # stage walk keeps conv indices aligned with models.vgg9
-    cin = convs[0]
+    cin = cfg.in_ch
     idx = 0
     for s in cfg.stages:
         if s == "MP":
             hw //= 2
             continue
-        if idx > 0:
+        if idx > 0 or rate:
             m, k, n = t * batch * hw * hw, 9 * cin, s
             name = f"conv{idx}"
             specs.append({

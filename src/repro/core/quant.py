@@ -8,6 +8,8 @@ de-quantizes accumulated data for the spiking phase (paper §II-B, §IV-D).
 This module provides:
   * fake_quant        — symmetric uniform fake-quantization with STE, used in
                         training (QAT) for both the SNN and LM paths.
+  * quant_levels      — fake_quant's integer levels and scale, for products
+                        that sum the levels exactly and scale once.
   * quantize/dequantize, pack_int4/unpack_int4 — storage-side int4 with two
     nibbles per int8 byte (HBM traffic is the TPU analogue of FPGA LUT/BRAM
     savings; see DESIGN.md §2).
@@ -53,10 +55,22 @@ def _scale(w, bits, axis):
     return jnp.maximum(amax, 1e-8) / qmax
 
 
-def _fake_quant_fwd_impl(w, bits, axis):
+def quant_levels(w: jax.Array, bits: int = 4, axis: int | None = None):
+    """The integer levels ``q`` (integer-valued, in w's dtype) and the scale
+    ``s`` of `fake_quant`, whose forward value is ``q * s``.
+
+    A product of 0/1 spikes with ``q`` sums small integers, exactly in
+    float32 and in any order while ``|sum| < 2**24``; scaling it once after
+    the sum gives the same bits whatever the summation order.
+    """
     qmin, qmax = _qrange(bits)
     s = _scale(w, bits, axis)
-    q = jnp.clip(jnp.round(w / s), qmin, qmax)
+    return jnp.clip(jnp.round(w / s), qmin, qmax), s
+
+
+def _fake_quant_fwd_impl(w, bits, axis):
+    _, qmax = _qrange(bits)
+    q, s = quant_levels(w, bits, axis)
     in_range = (jnp.abs(w) <= (qmax + 0.5) * s).astype(w.dtype)
     return q * s, in_range
 

@@ -10,8 +10,15 @@ Execution paths:
     hoists the input conv out of the timestep scan (bit-exact, the input is
     timestep-invariant — dense-core observation from the paper).
   * hybrid inference — dense core kernel (kernels/dense_conv_lif) for the
-    input layer + occupancy-gated spike_conv kernels for the spiking layers;
-    validated against the training path in tests.
+    direct-coded input layer + occupancy-gated spike_conv kernels for the
+    spiking layers; a rate-coded net's input layer is one more spiking layer
+    over the encoded input spikes. Validated against the training path in
+    tests.
+
+Rate coding draws each image's input spikes from a key of its own
+(`core.coding.rate_code_images`) whose base is the weights' ``"encoder"``
+leaf (a typed key; `init_vgg9` leaves it out, so a training tree stays
+differentiable) or, in `vgg9_forward`, its ``rng`` argument where given.
 
 Every forward returns per-layer spike counts (the Eq. 3 workload inputs and
 the Fig. 1 quantization-sparsity measurements).
@@ -25,9 +32,9 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core.coding import direct_code, rate_code
+from ..core.coding import direct_code, rate_code_images
 from ..core.lif import LIFParams, lif_step
-from ..core.quant import fake_quant
+from ..core.quant import fake_quant, quant_levels
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +91,17 @@ def init_vgg9(key, cfg: VGG9Config, dtype=jnp.float32) -> Dict:
     return params
 
 
+#: the params leaf that holds a rate-coded net's encoder base key
+ENCODER = "encoder"
+
+
+def _weights(params: Dict) -> Dict:
+    """The layer weights of ``params``, without the encoder key."""
+    if ENCODER not in params:
+        return params
+    return {k: v for k, v in params.items() if k != ENCODER}
+
+
 def quantized_view(params: Dict, cfg: VGG9Config) -> Dict:
     """QAT fake-quant view of the weights (paper §II-B): int-`quant_bits`
     weights, int8 biases, neuronal parameters untouched."""
@@ -110,9 +128,10 @@ def vgg9_forward(params: Dict, images: jax.Array, cfg: VGG9Config, *,
     """images [B,H,W,C] -> (logits [B,num_classes], spike counts per layer).
 
     BPTT-ready: the timestep loop is a lax.scan carrying membrane potentials
-    and previous spikes for every LIF layer.
+    and previous spikes for every LIF layer. Rate coding draws the input
+    spikes from ``rng``, else from the weights' ``"encoder"`` leaf.
     """
-    qp = quantized_view(params, cfg)
+    qp = quantized_view(_weights(params), cfg)
     lif = cfg.lif
     names = conv_names(cfg) + ["fc0", "fc1"]
     b = images.shape[0]
@@ -146,8 +165,10 @@ def vgg9_forward(params: Dict, images: jax.Array, cfg: VGG9Config, *,
             coded = direct_code(images, cfg.timesteps)
             currents_in = jax.vmap(lambda im: _conv(im, qp["conv0"]))(coded)
     else:  # rate coding: binary input spikes, conv0 acts as a sparse layer
-        assert rng is not None, "rate coding needs an rng key"
-        coded = rate_code(rng, images, cfg.timesteps)
+        if rng is None:
+            rng = params.get(ENCODER)
+        assert rng is not None, "rate coding needs an rng key or an 'encoder' leaf"
+        coded = rate_code_images(rng, images, cfg.timesteps)
         currents_in = jax.vmap(lambda sp: _conv(sp, qp["conv0"]))(coded)
 
     def timestep(carry, current0):
@@ -224,24 +245,44 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
     from ..kernels.spike_conv.ops import spike_conv2d_mapped
 
     interpret = interpret_mode()
-    qp = quantized_view(params, cfg)
+    qp = quantized_view(_weights(params), cfg)
     b = images.shape[0]
     t = cfg.timesteps
-
-    # Dense core: input layer, conv once + T fused LIF steps (one launch).
-    ks0 = plan.layer("conv0").kernel
-    spikes, _ = input_layer_conv_lif(
-        images, qp["conv0"]["w"], qp["conv0"]["b"],
-        num_steps=t, beta=cfg.beta, theta=cfg.theta,
-        block_m=ks0.block_m, block_n=ks0.block_n, interpret=interpret)
-    counts = {"conv0": jnp.sum(spikes)}
     # stats carry per-layer tile-skip measurements *and* per-request spike
     # counts ([B] vectors) so the serving engine can split the folded batch's
     # counters back out per request. Spikes are 0/1 floats, so the per-image
     # sums recombine exactly to the scalar `counts`.
+    counts: Dict[str, jax.Array] = {}
     stats: Dict[str, Dict[str, jax.Array]] = {}
-    if with_stats:
-        stats["conv0"] = {"out_spikes_per_image": spikes.sum(axis=(0, 2, 3, 4))}
+
+    if cfg.coding == "rate":
+        # Input spikes drawn per image; conv0 is a sparse layer over them.
+        with jax.named_scope("rate_encode"):
+            spikes = rate_code_images(params[ENCODER], images, t)
+        layers = [("conv", 0)] + _stage_plan(cfg)
+    else:
+        # Dense core: input layer, conv once + T fused LIF steps (one launch).
+        ks0 = plan.layer("conv0").kernel
+        spikes, _ = input_layer_conv_lif(
+            images, qp["conv0"]["w"], qp["conv0"]["b"],
+            num_steps=t, beta=cfg.beta, theta=cfg.theta,
+            block_m=ks0.block_m, block_n=ks0.block_n, interpret=interpret)
+        counts["conv0"] = jnp.sum(spikes)
+        if with_stats:
+            stats["conv0"] = {"out_spikes_per_image": spikes.sum(axis=(0, 2, 3, 4))}
+        layers = _stage_plan(cfg)
+
+    # A rate-coded int net's products are 0/1 spikes times the weights'
+    # integer levels, summed exactly in any order, then scaled once: its
+    # currents do not depend on the kernels' tiling, so a served answer is
+    # the reference's bit for bit even through T = 25 chaotic LIF steps.
+    exact = cfg.coding == "rate" and cfg.quant_bits > 0
+
+    def product_weights(name):
+        """(weights the layer's product takes, scale applied after it)."""
+        if not exact:
+            return qp[name]["w"], None
+        return quant_levels(params[name]["w"], cfg.quant_bits)
 
     def lif_scan_fused(cur_t, bias):
         """lax.scan of the conv-epilogue LIF over [T, rows, N] currents."""
@@ -259,16 +300,19 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
     # Sparse cores: timesteps folded into the batch — ONE occupancy-mapped
     # gated matmul launch per layer, then the sequential LIF recurrence.
     x = spikes.reshape((t * b,) + spikes.shape[2:])      # [T*B, H, W, C]
-    for kind, idx in _stage_plan(cfg):
+    for kind, idx in layers:
         if kind == "MP":
             x = _maxpool_spikes(x)
             continue
         name = f"conv{idx}"
         ks = plan.layer(name).kernel
+        w, scale = product_weights(name)
         cur, st = spike_conv2d_mapped(
-            x, qp[name]["w"],
+            x, w,
             block_m=ks.block_m, block_k=ks.block_k, block_n=ks.block_n,
             gate=ks.gate, interpret=interpret)           # [T*B, H, W, Cout]
+        if scale is not None:
+            cur = cur * scale
         _, h, w, cout = cur.shape
         s_seq = lif_scan_fused(cur.reshape(t, b * h * w, cout), qp[name]["b"])
         counts[name] = jnp.sum(s_seq)
@@ -283,11 +327,13 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
     # FC layers (sparse cores with URAM weights in the paper): same folding.
     flat = x.reshape(t * b, -1)
     for name in ("fc0", "fc1"):
-        w2d = qp[name]["w"]
+        w2d, scale = product_weights(name)
         in_per_image = flat.reshape(t, b, -1).sum(axis=(0, 2))
         # one launch, bias in the epilogue; float32 like the kernels (XLA's
         # default on a TPU would round the weights to bf16)
         cur = jnp.dot(flat, w2d, precision=jax.lax.Precision.HIGHEST)
+        if scale is not None:
+            cur = cur * scale
         s_seq = lif_scan_fused(cur.reshape(t, b, w2d.shape[-1]), qp[name]["b"])
         counts[name] = jnp.sum(s_seq)
         if with_stats:
@@ -305,20 +351,21 @@ def _infer_hybrid_fused(params: Dict, images: jax.Array, *, cfg: VGG9Config,
 
 def vgg9_infer_hybrid(params: Dict, images: jax.Array, cfg: VGG9Config, *,
                       plan=None, return_stats: bool = False):
-    """Fused inference via the TPU kernels: dense_conv_lif for the input
-    layer, occupancy-mapped spike_conv + conv-epilogue LIF for the spiking
-    layers. The whole graph is one jit (static `cfg`/`plan` hashing), with
+    """Fused inference via the TPU kernels: dense_conv_lif for a
+    direct-coded input layer, occupancy-mapped spike_conv + conv-epilogue
+    LIF for the spiking layers (a rate-coded net's conv0 among them, over
+    the input spikes `rate_code_images` draws from ``params["encoder"]``).
+    The whole graph is one jit (static `cfg`/`plan` hashing), with
     timesteps folded into the batch so every spiking layer issues a single
     gated-matmul launch instead of T.
 
-    Direct coding only. Numerics match vgg9_forward (tests assert).
+    Numerics match vgg9_forward (tests assert).
     Returns (logits, counts); with return_stats=True additionally returns the
     per-layer stats: tile-skip measurements (occupancy map included) of the
     occupancy-mapped kernels plus per-image input/output spike counts for
     every layer — the quantities the serving engine splits back out per
     request.
     """
-    assert cfg.coding == "direct"
     if plan is None:
         from ..core.hybrid import plan_vgg9_inference
         plan = plan_vgg9_inference(cfg, images.shape[0])
@@ -394,7 +441,6 @@ def vgg9_infer_hybrid_sharded(params: Dict, images: jax.Array, cfg: VGG9Config, 
         mesh: a mesh whose ``axis`` divides the batch (``B % ndev == 0``).
         plan: optional `HybridPlan` sized to the *local* batch ``B/ndev``.
     """
-    assert cfg.coding == "direct"
     b = images.shape[0]
     ndev = int(mesh.shape[axis])
     assert b % ndev == 0, f"batch {b} must divide the '{axis}' axis ({ndev})"

@@ -18,6 +18,12 @@ occupancy/skip counters are split back out per request:
   input-spike counts, priced with the plan's NC allocation and the FPGA
   power model (`core.energy.energy_per_image`).
 
+A rate-coded net (``cfg.coding == "rate"``) is served the same way: its
+weights carry the encoder's base key as their ``"encoder"`` leaf (a typed
+key; the runner refuses a rate config without it), the graph draws each
+image's input spikes from it, and its conv0 is one more
+mapped layer whose counters split per request like the others'.
+
 Data-mesh sharding: under an ambient compute mesh (`dist.context`) whose
 ``'data'`` axis divides the slot count, `run` switches to
 `vgg9_infer_hybrid_sharded` — the folded [T*B·H·W, K] matmuls split across
@@ -40,8 +46,8 @@ from ...core.energy import analytical_energy_per_image, energy_per_image
 from ...core.hybrid import HybridPlan, plan_vgg9_inference
 from ...core.workload import (conv_workload, dense_input_workload, fc_workload)
 from ...dist.context import current_mesh
-from ...models.vgg9 import (VGG9Config, conv_names, vgg9_infer_hybrid,
-                            vgg9_infer_hybrid_sharded)
+from ...models.vgg9 import (ENCODER, VGG9Config, conv_names,
+                            vgg9_infer_hybrid, vgg9_infer_hybrid_sharded)
 from ...obs.stages import Stages
 from ..api import (PAD_REQUEST_ID, Request, Result, SlotProgress, StepBudget,
                    StepReport)
@@ -105,6 +111,11 @@ class SNNRunner:
     """Fixed-slot spiking-VGG9 serving (`ModelRunner`)."""
 
     def __init__(self, cfg: VGG9Config, params):
+        if cfg.coding == "rate" and ENCODER not in params:
+            raise ValueError(
+                f"a rate-coded net's weights need the encoder's base key as "
+                f"their {ENCODER!r} leaf, e.g. params[{ENCODER!r}] = "
+                f"jax.random.key(seed)")
         self.cfg = cfg
         self.params = params
         self._plans: Dict[int, HybridPlan] = {}
@@ -326,11 +337,13 @@ class SNNRunner:
         wbytes_per = 0.5 if cfg.quant_bits == 4 else 4.0
         precision = "int4" if cfg.quant_bits == 4 else "fp32"
 
-        workloads = [dense_input_workload("conv0", hw, hw, convs[0], t)]
-        weight_bytes = [9 * cfg.in_ch * convs[0] * wbytes_per]
-        cin = convs[0]
-        for i, name in enumerate(conv_names(cfg)[1:], start=1):
-            workloads.append(conv_workload(name, convs[i], 9, in_spikes[name]))
+        workloads, weight_bytes, cin = [], [], cfg.in_ch
+        for i, name in enumerate(conv_names(cfg)):
+            if i == 0 and cfg.coding == "direct":
+                # the dense core's input layer: priced by its output fan
+                workloads.append(dense_input_workload(name, hw, hw, convs[0], t))
+            else:
+                workloads.append(conv_workload(name, convs[i], 9, in_spikes[name]))
             weight_bytes.append(9 * cin * convs[i] * wbytes_per)
             cin = convs[i]
         for name, d_in, d_out in (("fc0", flat, cfg.fc_dim),

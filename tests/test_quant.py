@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import (QTensor, dequantize, fake_quant, pack_int4,
-                              qat_params, quantize_int4, unpack_int4)
+                              qat_params, quant_levels, quantize_int4,
+                              unpack_int4)
 
 
 def test_pack_unpack_roundtrip():
@@ -38,6 +39,26 @@ def test_fake_quant_levels():
     w = jnp.linspace(-1, 1, 1000)
     out = fake_quant(w, 4, None)
     assert len(np.unique(np.asarray(out))) <= 15
+
+
+def test_quant_levels_products_over_spikes_are_exact_in_any_order():
+    """fake_quant(w) is q * s bit for bit, and 0/1 spikes times the levels q,
+    scaled after the sum, give the same bits in any summation order, where
+    the dequantized weights do not."""
+    rng = np.random.default_rng(3)
+    w = jnp.asarray(rng.normal(size=(4096, 8)).astype(np.float32))
+    q, s = quant_levels(w, 4)
+    np.testing.assert_array_equal(np.asarray(q * s), np.asarray(fake_quant(w, 4, None)))
+    assert set(np.unique(np.asarray(q)).tolist()) <= set(range(-7, 8))
+    x = jnp.asarray((rng.random((16, 4096)) < 0.5).astype(np.float32))
+    perm = rng.permutation(4096)
+    with jax.default_matmul_precision("highest"):
+        orders = [(x @ q) * s, (x[:, perm] @ q[perm]) * s,
+                  (x[:, :2048] @ q[:2048] + x[:, 2048:] @ q[2048:]) * s]
+        deq = [x @ (q * s), x[:, perm] @ (q * s)[perm]]
+    for other in orders[1:]:
+        np.testing.assert_array_equal(np.asarray(orders[0]), np.asarray(other))
+    assert np.any(np.asarray(deq[0]) != np.asarray(deq[1]))
 
 
 def test_fake_quant_ste_gradient():

@@ -167,3 +167,45 @@ def test_data_mesh_graph_compiles_over_four_devices(topo, mosaic, smoke):
     smoke.check_launches(smoke.kernel_launches(compiled.as_text()), cfg)
     per_device = compiled.memory_analysis()
     assert per_device is not None
+
+
+def _rate_graph(slots, one_chip):
+    """The rate-coded T = 25 graph (`vgg9_snn.RATE_CIFAR10`) at ``slots``,
+    compiled for one v5e: (kernel launches, bytes of temporaries,
+    arguments and outputs)."""
+    cfg = vgg9_snn.RATE_CIFAR10
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = dict(_param_shapes(cfg, one_chip),
+                  encoder=_shape(key.shape, key.dtype, one_chip))
+    compiled = vgg9._infer_hybrid_fused.lower(
+        params,
+        _shape((slots, cfg.img_hw, cfg.img_hw, cfg.in_ch), jnp.float32, one_chip),
+        cfg=cfg, plan=plan_vgg9_inference(cfg, slots), with_stats=True).compile()
+    mem = compiled.memory_analysis()
+    used = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
+    return compiled, used
+
+
+def test_rate_graph_compiles_at_the_cells_32_slots_and_fits_one_chip(
+        one_chip, mosaic, smoke):
+    """The rate-coded T = 25 graph at `rate_cifar10_int4.dense.offline`'s 32
+    slots: conv0 is a spike_matmul_mapped layer (no dense core), and the
+    program fits one v5e's 16 GB."""
+    cfg = vgg9_snn.RATE_CIFAR10
+    compiled, used = _rate_graph(32, one_chip)
+    counts = smoke.kernel_launches(compiled.as_text())
+    assert counts["dense_conv_lif"] == 0
+    assert counts["spike_matmul_mapped"] == len(cfg.conv_channels)
+    assert counts["lif_epilogue"] >= len(cfg.conv_channels) + 2
+    assert counts["tpu_custom_call"] == sum(counts[k] for k in smoke.KERNELS)
+    assert used < 0.9 * 16e9, used
+
+
+def test_rate_graph_at_64_slots_does_not_fit_one_chip(one_chip, mosaic):
+    """Why the rate cell takes 32 slots and not `dense.offline`'s 64: at 64
+    the T = 25 graph needs more than a v5e's 15.75 GB of HBM, and the
+    chip's compiler refuses it (the im2col of every layer is materialised
+    in float32 at T*B*H*W rows)."""
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="Ran out of memory in memory space hbm"):
+        _rate_graph(64, one_chip)
